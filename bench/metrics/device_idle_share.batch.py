@@ -1,0 +1,6 @@
+"""Share of the traced window in which no operation runs on the device."""
+from bench.metrics._common import idle_share
+
+
+def read(run):
+    return idle_share(run)

@@ -1,0 +1,12 @@
+"""Model FLOPs of every prefill and decode token in the traced window over
+the window's length times the chip's peak bf16 FLOP/s."""
+from bench.metrics._common import decode_steps, prefill_flops
+
+
+def read(run):
+    if run.reduced is None or run.spans is None:
+        return None
+    flops = sum(f for f, _ in decode_steps(run)) + prefill_flops(run)[0]
+    if flops <= 0:
+        return None
+    return flops / (run.reduced.window_s * run.peaks.flops) * 100.0
