@@ -15,14 +15,23 @@ All timestamps and durations are SECONDS relative to the trace origin;
 payload attributes are BYTES. Traces serialize to a versioned JSON
 document (`Trace.save` / `Trace.load`) and to Chrome's `trace_event`
 format (`Trace.save_chrome`) loadable in chrome://tracing or Perfetto.
+
+Serving-loop spans nest: `Trace.span` records each span's enclosing span
+as `attrs["parent"]` (an index into `events`), and while a profiler
+session runs it also opens a `jax.profiler.TraceAnnotation` named
+`serve.<kind>`, so the span lands on the profiler's host plane, on the
+clock of the device planes.
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import json
 import time
 from typing import Any
+
+import jax
 
 #: bump when the serialized event schema changes shape (golden traces and
 #: archived benchmark artifacts pin the version they were written with)
@@ -30,11 +39,35 @@ TRACE_SCHEMA_VERSION = 1
 
 #: every event kind the tracer emits; `compute`/`launch` occupy a device,
 #: `stage_in`/`exchange`/`writeback`/`transfer_out` occupy the shared
-#: transfer channel, `compile`/`cache_hit` are FaceCache accounting, and
-#: `prefill_step`/`decode_step` are per-slot serving-loop latencies
+#: transfer channel, `compile`/`cache_hit` are FaceCache accounting (and
+#: `compile` on resource `"jax"` one traced JAX program),
+#: `prefill_step`/`decode_step` are per-slot serving-loop latencies, and
+#: the rest are the serving loop's host work: a request's wait in the
+#: gateway queue, an admission round, a gateway step and its token
+#: bookkeeping, a plan-cache consult and its planner solve on a miss, and
+#: the engine's step preparation, host syncs and per-slot bookkeeping
 EVENT_KINDS = ("compute", "launch", "stage_in", "exchange", "writeback",
                "transfer_out", "compile", "cache_hit", "prefill_step",
-               "decode_step")
+               "decode_step", "queue", "admit", "gateway_step", "record",
+               "price", "solve", "decode_prep", "decode_sync",
+               "decode_record", "prefill_sync")
+
+#: the `jax.monitoring` duration events `Trace.record_jax_compile` reads
+JAXPR_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+_NO_SPAN = contextlib.nullcontext()
+
+
+def maybe_span(tracer: Trace | None, kind: str, name: str, resource: str,
+               **attrs: Any):
+    """`tracer.span(...)`, or when `tracer` is None a context that records
+    nothing, opens no annotation and yields None: the serving loop's
+    untraced path."""
+    if tracer is None:
+        return _NO_SPAN
+    return tracer.span(kind, name, resource, **attrs)
 
 
 @dataclasses.dataclass
@@ -86,6 +119,8 @@ class Trace:
         self.meta: dict = dict(meta or {})
         self.events: list[TraceEvent] = []
         self._origin = time.perf_counter()
+        self._open: list[int] = []       # indices of the open spans
+        self._compiles: dict[str, TraceEvent] = {}  # latest per program
 
     def now(self) -> float:
         """Seconds since the trace origin (monotonic, `perf_counter`)."""
@@ -101,6 +136,49 @@ class Trace:
                         self.now() if t1 is None else t1, group, attrs)
         self.events.append(ev)
         return ev
+
+    @contextlib.contextmanager
+    def span(self, kind: str, name: str, resource: str, group: int = -1,
+             **attrs: Any):
+        """Record a span around the `with` body, as `add` does; yields the
+        event, whose `attrs` the body may extend. The enclosing open span's
+        index in `events` is stored as `attrs["parent"]`. While a profiler
+        session runs, the body also sits inside a
+        `jax.profiler.TraceAnnotation` named `serve.<kind>`."""
+        if self._open:
+            attrs["parent"] = self._open[-1]
+        t0 = self.now()
+        ev = TraceEvent(kind, name, resource, t0, t0, group, attrs)
+        self._open.append(len(self.events))
+        self.events.append(ev)
+        annotation = jax.profiler.TraceAnnotation
+        try:
+            if annotation.is_enabled():
+                with annotation(f"serve.{kind}"):
+                    yield ev
+            else:
+                yield ev
+        finally:
+            ev.t1 = self.now()
+            self._open.pop()
+
+    def record_jax_compile(self, event: str, duration_s: float,
+                           fun_name: str = "", **_: Any) -> None:
+        """A `jax.monitoring` duration listener: one `compile` event on
+        resource `"jax"` per program JAX traces (ending now, lasting the
+        tracing; a program traced inside another's tracing counts too),
+        with `backend_compile_s` added when the backend then compiles it
+        (not on a persistent-cache hit)."""
+        if event == JAXPR_TRACE_EVENT:
+            t1 = self.now()
+            attrs = {"parent": self._open[-1]} if self._open else {}
+            self._compiles[fun_name] = self.add(
+                "compile", fun_name, "jax", t1 - duration_s, t1, **attrs)
+        elif event == BACKEND_COMPILE_EVENT:
+            # the backend names the program `jit(<fun_name>)`
+            ev = self._compiles.get(fun_name[fun_name.find("(") + 1:-1])
+            if ev is not None:
+                ev.attrs["backend_compile_s"] = duration_s
 
     def instant(self, kind: str, name: str, resource: str, group: int = -1,
                 **attrs: Any) -> TraceEvent:

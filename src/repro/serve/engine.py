@@ -30,6 +30,7 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 
+from ..dispatch.trace.events import maybe_span
 from ..models import ModelConfig, Shardings, forward, init_cache
 
 
@@ -197,14 +198,26 @@ class ServeEngine:
     def attach_tracer(self, tracer) -> None:
         """Attach a `dispatch.trace.Trace`: the serving loop records one
         `prefill_step` span per admission (with the slot and prompt
-        length) and one `decode_step` span per batched step (with the
-        live slots and per-slot positions — per-slot latency
-        attribution: every live slot advanced one token in that span).
-        Under `engine="dispatch"` the tracer also threads through both
-        planner-routed steps into `PlanExecutor.run` (per-node compute
-        spans, channel occupancy) and the FaceCache (compile vs
-        cache-hit). Pass None to detach."""
+        length; it ends once the first token is on the host, a wait held
+        by its child `prefill_sync`) and one `decode_step` span per
+        batched step (with the live slots and per-slot positions —
+        per-slot latency attribution: every live slot advanced one token
+        in that span), whose child `decode_sync` holds the step's one
+        host sync; `decode_prep` (live mask, key split) precedes it and
+        `decode_record` (per-slot tokens and finishes) follows it. While
+        attached, every program JAX traces in the process is recorded as
+        a `compile` event on resource `"jax"` (a `jax.monitoring`
+        listener, removed on detach). Under `engine="dispatch"` the
+        tracer also threads through both planner-routed steps into
+        `PlanExecutor.run` (per-node compute spans, channel occupancy)
+        and the FaceCache (compile vs cache-hit). Pass None to detach."""
+        if self.tracer is not None:
+            jax.monitoring.unregister_event_duration_listener(
+                self.tracer.record_jax_compile)
         self.tracer = tracer
+        if tracer is not None:
+            jax.monitoring.register_event_duration_secs_listener(
+                tracer.record_jax_compile)
         if self.engine == "dispatch":
             self._decode.tracer = tracer
             if self.prefill_plan is not None:
@@ -248,14 +261,14 @@ class ServeEngine:
         if req.max_new_tokens < 1:
             raise ValueError(
                 f"max_new_tokens must be >= 1, got {req.max_new_tokens}")
-        t0 = self.tracer.now() if self.tracer is not None else 0.0
-        logits, self.cache = self._prefill_one(
-            self.params, self.cache, req.prompt, jnp.int32(slot))
-        if self.tracer is not None:
-            self.tracer.add("prefill_step", f"req{req.rid}", "engine", t0,
-                            slot=slot, prompt_len=plen)
-        self.key, k = jax.random.split(self.key)
-        first = int(sample(logits, k, self.temperature))
+        name = f"req{req.rid}"
+        with maybe_span(self.tracer, "prefill_step", name, "engine",
+                        slot=slot, prompt_len=plen):
+            logits, self.cache = self._prefill_one(
+                self.params, self.cache, req.prompt, jnp.int32(slot))
+            self.key, k = jax.random.split(self.key)
+            with maybe_span(self.tracer, "prefill_sync", name, "engine"):
+                first = int(sample(logits, k, self.temperature))
         req.out_tokens.append(first)
         # the first token can already exhaust the budget or hit EOS —
         # finish here and leave the slot free instead of decoding (and
@@ -272,36 +285,45 @@ class ServeEngine:
 
     def step(self) -> int:
         """One batched decode step for all live slots. Returns #live."""
-        live = jnp.asarray(self.slot_live)
         if not any(self.slot_live):
             return 0
-        self.key, k = jax.random.split(self.key)
-        t0 = self.tracer.now() if self.tracer is not None else 0.0
-        self.last_tok, self.cache, self.slot_pos = self._decode(
-            self.params, self.cache, self.last_tok, self.slot_pos, live, k)
-        # ONE host sync per step: tokens and positions fetched together.
-        # (The finish loop's per-slot int(self.slot_pos[slot]) and the
-        # tracer's second device_get were each an extra device round-trip.)
-        toks, pos = jax.device_get((self.last_tok[:, 0], self.slot_pos))
-        if self.tracer is not None:      # device_get synced: span = real
-            self._step_no += 1           # step latency, one token per slot
-            self.tracer.add(
-                "decode_step", f"step{self._step_no}", "engine", t0,
-                n_live=sum(self.slot_live),
-                slots=[s for s, lv in enumerate(self.slot_live) if lv],
-                positions=[int(p) for p, lv in zip(pos, self.slot_live)
-                           if lv])
-        for slot, req in enumerate(self.slot_req):
-            if req is None or not self.slot_live[slot]:
-                continue
-            t = int(toks[slot])
-            req.out_tokens.append(t)
-            limit_hit = len(req.out_tokens) >= req.max_new_tokens
-            eos_hit = self.eos_id is not None and t == self.eos_id
-            if limit_hit or eos_hit or int(pos[slot]) >= self.max_len - 1:
-                req.done = True
-                self.slot_live[slot] = False
-                self.slot_req[slot] = None
+        tracer = self.tracer
+        if tracer is not None:
+            self._step_no += 1
+        name = f"step{self._step_no}"
+        with maybe_span(tracer, "decode_prep", name, "engine"):
+            live = jnp.asarray(self.slot_live)
+            self.key, k = jax.random.split(self.key)
+        with maybe_span(tracer, "decode_step", name, "engine") as span:
+            self.last_tok, self.cache, self.slot_pos = self._decode(
+                self.params, self.cache, self.last_tok, self.slot_pos, live,
+                k)
+            # ONE host sync per step: tokens and positions fetched
+            # together. (The finish loop's per-slot int(self.slot_pos[slot])
+            # and the tracer's second device_get were each an extra device
+            # round-trip.)
+            with maybe_span(tracer, "decode_sync", name, "engine"):
+                toks, pos = jax.device_get((self.last_tok[:, 0],
+                                            self.slot_pos))
+            if span is not None:         # device_get synced: span = real
+                span.attrs.update(       # step latency, one token per slot
+                    n_live=sum(self.slot_live),
+                    slots=[s for s, lv in enumerate(self.slot_live) if lv],
+                    positions=[int(p) for p, lv in zip(pos, self.slot_live)
+                               if lv])
+        with maybe_span(tracer, "decode_record", name, "engine"):
+            for slot, req in enumerate(self.slot_req):
+                if req is None or not self.slot_live[slot]:
+                    continue
+                t = int(toks[slot])
+                req.out_tokens.append(t)
+                limit_hit = len(req.out_tokens) >= req.max_new_tokens
+                eos_hit = self.eos_id is not None and t == self.eos_id
+                if (limit_hit or eos_hit
+                        or int(pos[slot]) >= self.max_len - 1):
+                    req.done = True
+                    self.slot_live[slot] = False
+                    self.slot_req[slot] = None
         return sum(self.slot_live)
 
     def serve(self, requests: list[Request]) -> list[Request]:

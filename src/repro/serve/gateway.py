@@ -54,6 +54,7 @@ from ..dispatch import workloads
 from ..dispatch.placement import Plan, plan as plan_placement
 from ..dispatch.plan_cache import PlanCache, batch_signature
 from ..dispatch.schedule import make_schedule
+from ..dispatch.trace.events import maybe_span
 from .dispatch_engine import dims_for_config
 from .engine import Request, ServeEngine
 
@@ -287,6 +288,8 @@ class Gateway:
         self.clock = clock
         self._dims = dims_for_config(engine.cfg, engine.n_slots,
                                      engine.max_len)
+        self.tracer = None               # dispatch.trace.Trace | None
+        self._queued_t0: dict[int, float] = {}   # rid -> tracer time
         self.running: dict[int, GatewayRequest] = {}
         self.finished: list[GatewayRequest] = []
         self.rejected: list[GatewayRequest] = []
@@ -316,8 +319,22 @@ class Gateway:
                               pos_bucket=self.pos_bucket,
                               topology=self.devices,
                               window=self._dims.window)
-        return self.plans.get_or_plan(
-            key, lambda: self._price_decode(n_live, key[2]))
+        return self._consult("decode", key,
+                             lambda: self._price_decode(n_live, key[2]))
+
+    def _consult(self, phase: str, key, builder) -> PricedPlan:
+        """`self.plans.get_or_plan(key, builder)`; traced, a `price` span
+        (with `phase` and `hit`) around it and a `solve` span around the
+        builder on a miss."""
+        tracer = self.tracer
+        if tracer is None:
+            return self.plans.get_or_plan(key, builder)
+        with tracer.span("price", phase, "planner", phase=phase,
+                         hit=key in self.plans):
+            def solve():
+                with tracer.span("solve", phase, "planner"):
+                    return builder()
+            return self.plans.get_or_plan(key, solve)
 
     def _price_decode(self, n_live: int, kv_len: int) -> PricedPlan:
         dims = dataclasses.replace(self._dims, batch=n_live,
@@ -343,8 +360,8 @@ class Gateway:
                               pos_bucket=self.pos_bucket,
                               topology=self.devices,
                               window=self._dims.window)
-        return self.plans.get_or_plan(
-            key, lambda: self._price_prefill(splits)).priced_s
+        return self._consult("prefill", key,
+                             lambda: self._price_prefill(splits)).priced_s
 
     def _price_prefill(self, splits: list[int]) -> PricedPlan:
         dims = dataclasses.replace(self._dims, batch=1)
@@ -387,8 +404,11 @@ class Gateway:
         rejected here — the engine would raise), then offer it to the
         bounded priority queue under the reject/shed policy. Returns
         True when queued; otherwise the request (or the shed victim)
-        ends in state `"rejected"` with `reject_reason` set."""
+        ends in state `"rejected"` with `reject_reason` set. Traced, each
+        request's `queue` span starts here."""
         self.submitted += 1
+        if self.tracer is not None:
+            self._queued_t0[greq.rid] = self.tracer.now()
         if int(greq.prompt.shape[0]) >= self.engine.max_len:
             self._reject(greq, "prompt-too-long")
             return False
@@ -408,6 +428,13 @@ class Gateway:
         greq.state = "rejected"
         greq.reject_reason = reason
         self.rejected.append(greq)
+        self._end_queue_span(greq, reason=reason)
+
+    def _end_queue_span(self, greq: GatewayRequest, **attrs) -> None:
+        t0 = self._queued_t0.pop(greq.rid, None)
+        if self.tracer is not None and t0 is not None:
+            self.tracer.add("queue", f"req{greq.rid}", "gateway", t0,
+                            rid=greq.rid, **attrs)
 
     def admit_pending(self) -> int:
         """Drain the queue into free slots in priority order under the
@@ -417,35 +444,41 @@ class Gateway:
         decode-step price — both sides priced by the plan cache. The
         first admission per gap always proceeds when a slot is free (no
         starvation), and with no live decode there is nothing to stall,
-        so the budget only binds while decodes are in flight."""
+        so the budget only binds while decodes are in flight. Traced, the
+        round is one `admit` span (with `admitted` and the `queued` count
+        left) and each popped request's `queue` span ends at its pop."""
         n = 0
         spent = 0.0
-        while self.engine.n_free > 0 and len(self.queue) > 0:
-            live = self.engine.n_slots - self.engine.n_free
-            if live == 0:
-                budget = math.inf
-            elif self.max_stall_s is not None:
-                budget = self.max_stall_s
-            else:
-                budget = self.stall_factor * self.decode_price_s()
-            greq = self.queue.peek()
-            price = self.prefill_price_s(int(greq.prompt.shape[0]))
-            if n > 0 and spent + price > budget:
-                break
-            greq = self.queue.pop()
-            req = Request(greq.rid, greq.prompt, greq.max_new_tokens)
-            greq.request = req
-            self.engine.admit(req)       # a slot is free: always True
-            t = self.clock()
-            greq.admit_s = t
-            greq.state = "running"
-            greq.token_times.append(t)   # first token sampled at admit
-            spent += price
-            n += 1
-            if req.done:                 # budget/EOS met by first token
-                self._finish(greq, t)
-            else:
-                self.running[greq.rid] = greq
+        with maybe_span(self.tracer, "admit", "admit", "gateway") as span:
+            while self.engine.n_free > 0 and len(self.queue) > 0:
+                live = self.engine.n_slots - self.engine.n_free
+                if live == 0:
+                    budget = math.inf
+                elif self.max_stall_s is not None:
+                    budget = self.max_stall_s
+                else:
+                    budget = self.stall_factor * self.decode_price_s()
+                greq = self.queue.peek()
+                price = self.prefill_price_s(int(greq.prompt.shape[0]))
+                if n > 0 and spent + price > budget:
+                    break
+                greq = self.queue.pop()
+                self._end_queue_span(greq)
+                req = Request(greq.rid, greq.prompt, greq.max_new_tokens)
+                greq.request = req
+                self.engine.admit(req)   # a slot is free: always True
+                t = self.clock()
+                greq.admit_s = t
+                greq.state = "running"
+                greq.token_times.append(t)   # first token sampled at admit
+                spent += price
+                n += 1
+                if req.done:             # budget/EOS met by first token
+                    self._finish(greq, t)
+                else:
+                    self.running[greq.rid] = greq
+            if span is not None:
+                span.attrs.update(admitted=n, queued=len(self.queue))
         return n
 
     def _finish(self, greq: GatewayRequest, t: float) -> None:
@@ -461,19 +494,25 @@ class Gateway:
         current signature through the plan cache (the per-step planner
         consult the cache amortizes), advances every live slot one
         token, records token times, and finalizes finished requests.
-        Returns the number of live slots after the step."""
-        if self.running:
-            self.last_decode_price_s = self.decode_price_s()
-        self.steps += 1
-        live = self.engine.step()
-        t = self.clock()
-        for rid, greq in list(self.running.items()):
-            req = greq.request
-            if len(req.out_tokens) > len(greq.token_times):
-                greq.token_times.append(t)
-            if req.done:
-                del self.running[rid]
-                self._finish(greq, t)
+        Returns the number of live slots after the step. Traced, the step
+        is one `gateway_step` span, its bookkeeping a child `record`."""
+        tracer = self.tracer
+        with maybe_span(tracer, "gateway_step", f"step{self.steps + 1}",
+                        "gateway"):
+            if self.running:
+                self.last_decode_price_s = self.decode_price_s()
+            self.steps += 1
+            live = self.engine.step()
+            with maybe_span(tracer, "record", f"step{self.steps}",
+                            "gateway"):
+                t = self.clock()
+                for rid, greq in list(self.running.items()):
+                    req = greq.request
+                    if len(req.out_tokens) > len(greq.token_times):
+                        greq.token_times.append(t)
+                    if req.done:
+                        del self.running[rid]
+                        self._finish(greq, t)
         return live
 
     def run(self, requests: Sequence[GatewayRequest],
@@ -517,11 +556,18 @@ class Gateway:
     # metrics
     # ------------------------------------------------------------- #
     def attach_tracer(self, tracer) -> None:
-        """Attach a `dispatch.trace.Trace` to the underlying engine (see
-        `ServeEngine.attach_tracer`): admissions record `prefill_step`
-        spans and batched steps record `decode_step` spans — under the
-        dispatch engine the per-stage compute spans too — the timeline
-        `gateway_bench`'s fidelity gate replays. Pass None to detach."""
+        """Attach a `dispatch.trace.Trace` to the gateway and the
+        underlying engine (see `ServeEngine.attach_tracer`): admissions
+        record `prefill_step` spans and batched steps record
+        `decode_step` spans — under the dispatch engine the per-stage
+        compute spans too — the timeline `gateway_bench`'s fidelity gate
+        replays. The gateway adds one `queue` span per request (from
+        `submit` to its pop, or to its rejection with the `reason`), an
+        `admit` span per admission round, a `gateway_step` span per step
+        with its child `record`, and a `price` span per plan-cache
+        consult with a child `solve` on a miss. Pass None to detach."""
+        self.tracer = tracer
+        self._queued_t0.clear()
         self.engine.attach_tracer(tracer)
 
     def _met_slo(self, greq: GatewayRequest) -> bool:
