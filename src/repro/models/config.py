@@ -89,7 +89,10 @@ class ModelConfig:
     remat: bool = True
     # scan over groups of `remat_group` pattern-repeats: boundaries are
     # saved every remat_group blocks (K-fewer stacked residuals; backward
-    # recomputes the group). Must divide n_blocks.
+    # recomputes the group). Must divide n_blocks. Only the uncached
+    # forward groups: a cached one (prefill, decode) is never
+    # differentiated and scans one block at a time, since a group's
+    # weight slice would be copied out of the stack on every iteration.
     remat_group: int = 1
     opt_moment_dtype: str = "float32"
     # attention chunking for long sequences (pure-JAX flash)

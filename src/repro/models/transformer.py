@@ -1,9 +1,11 @@
 """Model assembly: params, forward (train / prefill / decode), loss.
 
 The decoder stack scans over repeats of the config's layer pattern (blocks);
-heterogeneous stacks (jamba) unroll the pattern inside the scan body. Each
-block is rematerialized. Cache tensors ride the scan as xs/ys so decode
-state stays stacked and shardable.
+heterogeneous stacks (jamba) unroll the pattern inside the scan body. The
+uncached forward scans groups of `remat_group` blocks, each group
+rematerialized; a cached forward scans one block at a time (see
+`stack_forward`). Cache tensors ride the scan as xs/ys so decode state
+stays stacked and shardable.
 
 Forward modes:
   * cache=None, S tokens      -> training / eval forward
@@ -263,61 +265,53 @@ def block_forward(x, spec: LayerSpec, p, cfg: ModelConfig, shd: Shardings,
 
 def stack_forward(x, params, cfg: ModelConfig, shd: Shardings, rope,
                   cache_layers, index, width, encoder_out):
-    """Scan over groups of `remat_group` blocks; the pattern (and the
-    group) is unrolled inside the rematerialized body, so activations are
-    saved only at group boundaries (n_blocks/remat_group stacked residuals
-    instead of n_blocks — the §Perf memory-term lever)."""
+    """Scan the stack one block per iteration, or, uncached, over groups of
+    `remat_group` blocks.
+
+    The uncached forward (training, eval) unrolls the pattern and the
+    group inside the rematerialized body, so its backward saves
+    activations only at group boundaries (n_blocks/remat_group stacked
+    residuals instead of n_blocks — the §Perf memory-term lever).
+
+    A cached forward (prefill, decode) is never differentiated, so the
+    grouping saves nothing there, and it costs: a group's slice of a
+    stacked leaf has one user per block, so XLA copies the whole group of
+    weights (and cache) out of the stack on every iteration instead of
+    fusing each block's slice into its matmul. So it scans the stacked
+    leaves as they are stored, one block at a time."""
     pattern = cfg.layer_pattern()
     have_cache = cache_layers is not None
-    g = max(cfg.remat_group, 1)
+    g = 1 if have_cache else max(cfg.remat_group, 1)
     if cfg.n_blocks % g != 0:
         g = 1
     n_steps = cfg.n_blocks // g
 
-    def regroup(leaf):
-        return leaf.reshape((n_steps, g) + leaf.shape[1:])
-
-    def ungroup(leaf):
-        return leaf.reshape((cfg.n_blocks,) + leaf.shape[2:])
-
     def body(carry, xs):
         xc, aux_acc = carry
         layer_ps, cache_slices = xs
-        new_groups = []
+        new_slices = []
         for j in range(g):
             lp = (jax.tree.map(lambda l: l[j], layer_ps) if g > 1
                   else layer_ps)
-            cs = (jax.tree.map(lambda l: l[j], cache_slices)
-                  if have_cache and g > 1 else cache_slices)
-            new_slices = []
             for i, spec in enumerate(pattern):
-                sl = cs[i] if have_cache else None
+                sl = cache_slices[i] if have_cache else None
                 xc, new_sl, aux = block_forward(
                     xc, spec, lp[i], cfg, shd, rope, sl, index, width,
                     encoder_out)
                 aux_acc = aux_acc + aux
-                new_slices.append(new_sl if have_cache else 0)
-            new_groups.append(tuple(new_slices) if have_cache else 0)
-        if have_cache and g > 1:
-            ys = jax.tree.map(lambda *ls: jnp.stack(ls), *new_groups)
-        else:
-            ys = new_groups[-1]
-        return (xc, aux_acc), ys
+                new_slices.append(new_sl)
+        return (xc, aux_acc), (tuple(new_slices) if have_cache else 0)
 
     if cfg.remat:
         body = jax.checkpoint(body)
 
-    layer_xs = (jax.tree.map(regroup, params["layers"]) if g > 1
-                else params["layers"])
-    if have_cache:
-        cache_xs = (jax.tree.map(regroup, tuple(cache_layers)) if g > 1
-                    else tuple(cache_layers))
-    else:
-        cache_xs = _zeros_xs(cfg, n_steps)
+    layer_xs = params["layers"]
+    if g > 1:
+        layer_xs = jax.tree.map(
+            lambda l: l.reshape((n_steps, g) + l.shape[1:]), layer_xs)
+    cache_xs = tuple(cache_layers) if have_cache else _zeros_xs(cfg, n_steps)
     (x, aux), new_cache = jax.lax.scan(
         body, (x, jnp.zeros((), jnp.float32)), (layer_xs, cache_xs))
-    if have_cache and g > 1:
-        new_cache = jax.tree.map(ungroup, new_cache)
     return x, (list(new_cache) if have_cache else None), aux
 
 

@@ -220,9 +220,11 @@ def test_moe_aux_loss_bounds():
 # §Perf optimizations: numerical-equivalence regressions
 # --------------------------------------------------------------------- #
 
-def test_remat_group_equivalence_f32():
-    """remat_group is a pure memory/recompute trade: forward and grads
-    must be EXACT in f32 (EXPERIMENTS.md §Perf llama3 iteration)."""
+@pytest.mark.parametrize("mode", ["train", "serve"])
+def test_remat_group_equivalence_f32(mode):
+    """remat_group is a pure memory/recompute trade: EXACT in f32
+    (EXPERIMENTS.md §Perf llama3 iteration). `train`: forward and grads;
+    `serve`: prefill and three decode steps through the cache."""
     import dataclasses
     from repro.models import lm_loss
     base = dataclasses.replace(REDUCED["granite-3-8b"], n_layers=4,
@@ -240,9 +242,23 @@ def test_remat_group_equivalence_f32():
             return lm_loss(lg, labels, aux)
         return jax.value_and_grad(f)(params)
 
-    l1, g1 = loss(base)
-    l2, gg = loss(g2)
-    assert float(l1) == float(l2)
+    def served(cfg):
+        cache = init_cache(cfg, 2, 32, SHD)
+        lg, cache, _ = forward(params, cfg, SHD, tokens=toks[:, :13],
+                               cache=cache)
+        out = [lg]
+        for t in range(13, 16):
+            lg, cache, _ = forward(params, cfg, SHD,
+                                   tokens=toks[:, t:t + 1], cache=cache)
+            out.append(lg)
+        return out
+
+    if mode == "train":
+        l1, g1 = loss(base)
+        l2, gg = loss(g2)
+        assert float(l1) == float(l2)
+    else:
+        g1, gg = served(base), served(g2)
     for a, b in zip(jax.tree.leaves(g1), jax.tree.leaves(gg)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 

@@ -604,6 +604,50 @@ def test_decode_step_shapes(setup):
     assert int(cache["index"]) == 9
 
 
+def _outer_scan_lengths(jaxpr):
+    """Lengths of the scans not nested inside another scan."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            found.append(eqn.params["length"])
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _outer_scan_lengths(sub)
+    return found
+
+
+def test_cached_forward_scans_one_block():
+    """The engine's prefill and decode scan the stacked layers one block
+    per iteration whatever `remat_group` is (a group's weight slice would
+    be copied out of the stack each iteration); the uncached forward
+    still scans groups."""
+    import dataclasses
+    import functools
+    import types
+    from repro.models import forward, init_cache
+    cfg = dataclasses.replace(REDUCED["granite-3-8b"], n_layers=4,
+                              remat_group=2)
+    assert cfg.n_blocks == 4
+    params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg,
+                                                SHD))
+    cache = jax.eval_shape(lambda: init_cache(cfg, 2, 32, SHD))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
+    # the engine's own steps, bound to a stand-in so no cache is allocated
+    engine = types.SimpleNamespace(cfg=cfg, shd=SHD, temperature=0.0,
+                                   max_len=32)
+    decode = functools.partial(ServeEngine._decode_step_fn, engine)
+    prefill = functools.partial(ServeEngine._prefill_one_fn, engine)
+    live = jax.ShapeDtypeStruct((2,), jnp.bool_)
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32)
+    jp = jax.make_jaxpr(decode)(params, cache, i32(2, 1), i32(2), live, key)
+    assert _outer_scan_lengths(jp.jaxpr) == [cfg.n_blocks]
+    jp = jax.make_jaxpr(prefill)(params, cache, i32(8), i32())
+    assert _outer_scan_lengths(jp.jaxpr) == [cfg.n_blocks]
+    jp = jax.make_jaxpr(lambda p, t: forward(p, cfg, SHD, tokens=t))(
+        params, i32(2, 8))
+    assert _outer_scan_lengths(jp.jaxpr) == [cfg.n_blocks // 2]
+
+
 # ------------------------------------------------------------------ #
 # ISSUE-7 serve-loop bugfix regressions
 # ------------------------------------------------------------------ #

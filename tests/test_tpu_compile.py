@@ -88,35 +88,67 @@ def test_kernel_compiles_for_v5e(name, one_chip):
     assert "tpu_custom_call" in text, f"{name}: no Mosaic kernel"
 
 
-def test_granite_decode_step_fits_one_v5e(one_chip):
-    """The engine's fused decode step for granite-3-8b at published widths,
-    cut to 20 of 40 layers, 8 slots x 2048 positions: compiles for one
-    v5e and fits its HBM (weights, KV cache, the new cache and
-    temporaries)."""
+@pytest.fixture(scope="module")
+def granite_20l(one_chip):
+    """The engine's fused steps for granite-3-8b at published widths, cut
+    to 20 of 40 layers, 8 slots x 2048 positions, compiled for one v5e:
+    (compiled(program), params), `program` "decode" or "prefill" (one
+    256-token prompt into one slot)."""
     from repro.configs.granite_3_8b import CONFIG
     from repro.models import Shardings, init_cache
     from repro.models.transformer import param_shape_structs
     from repro.serve import ServeEngine
 
     cfg = dataclasses.replace(CONFIG, n_layers=20)
-    shd = Shardings(None)
     slots, max_len = 8, 2048
     on_chip = lambda t: jax.tree.map(
         lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
         t)
     params = on_chip(param_shape_structs(cfg))
     cache = on_chip(jax.eval_shape(lambda: init_cache(cfg, slots, max_len)))
-    vec = lambda dt: jax.ShapeDtypeStruct((slots,), dt, sharding=one_chip)
-    tokens = jax.ShapeDtypeStruct((slots, 1), jnp.int32, sharding=one_chip)
-    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
-    # the engine's own step, bound to a stand-in so no cache is allocated
-    engine = types.SimpleNamespace(cfg=cfg, shd=shd, temperature=0.0)
-    step = functools.partial(ServeEngine._decode_step_fn, engine)
-    compiled = jax.jit(step).lower(params, cache, tokens, vec(jnp.int32),
-                                   vec(jnp.bool_), key).compile()
-    mem = compiled.memory_analysis()
+    arg = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,
+                                                 sharding=one_chip)
+    # the engine's own steps, bound to a stand-in so no cache is allocated
+    engine = types.SimpleNamespace(cfg=cfg, shd=Shardings(None),
+                                   temperature=0.0, max_len=max_len)
+
+    @functools.cache
+    def compiled(program):
+        if program == "decode":
+            step = functools.partial(ServeEngine._decode_step_fn, engine)
+            args = (arg((slots, 1), jnp.int32), arg((slots,), jnp.int32),
+                    arg((slots,), jnp.bool_), arg((2,), jnp.uint32))
+        else:
+            step = functools.partial(ServeEngine._prefill_one_fn, engine)
+            args = (arg((256,), jnp.int32), arg((), jnp.int32))
+        return jax.jit(step).lower(params, cache, *args).compile()
+
+    return compiled, params
+
+
+def test_granite_decode_step_fits_one_v5e(granite_20l):
+    """The decode step compiles for one v5e and fits its HBM (weights, KV
+    cache, the new cache and temporaries)."""
+    compiled, params = granite_20l
+    mem = compiled("decode").memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     weights = sum(s.size * s.dtype.itemsize for s in jax.tree.leaves(params))
     assert weights > 8e9, weights              # published widths, 20 layers
     assert total < V5E_HBM_BYTES, (total, mem)
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_cached_steps_copy_no_weights(granite_20l, program):
+    """A cached step scans one layer at a time, so each layer's weight
+    slice fuses into its matmul: the temporaries stay below one layer's
+    weights (a scan over groups of `remat_group` layers copied each
+    group out of the stack, about 2.9 GB here)."""
+    compiled, params = granite_20l
+    layers = params["layers"]
+    n_blocks = jax.tree.leaves(layers)[0].shape[0]
+    layer_bytes = sum(s.size * s.dtype.itemsize
+                      for s in jax.tree.leaves(layers)) // n_blocks
+    assert layer_bytes > 3.9e8, layer_bytes
+    mem = compiled(program).memory_analysis()
+    assert mem.temp_size_in_bytes < layer_bytes, (layer_bytes, mem)
