@@ -3,8 +3,10 @@ reference, and the result line.
 
 The cell's entry in `BENCHMARK.json` names its configuration and traffic
 mix; both are files found by name under this directory, as are the metric
-readers (`metrics/<name>.py`) and the reference of the configuration's
-family (`reference/<family>.py`). Nothing here names a cell.
+readers (`metrics/<name>.py`) and, by the configuration's `family`, the
+program config and weight layout (`families/<family>.py`), the reference
+(`reference/<family>.py`) and its counts. Nothing here names a cell or a
+family.
 
 The window drives `Gateway` over `ServeEngine(engine="jit")` through the
 gateway's public calls, in the order `Gateway.run` makes them: requests are
@@ -47,8 +49,9 @@ def log(msg: str) -> None:
 
 def load_module(path: pathlib.Path):
     """Import a file by path (names may hold dots and dashes)."""
+    name = f"bench_{path.parent.name}_{path.stem}"
     spec = importlib.util.spec_from_file_location(
-        "bench_" + path.stem.replace(".", "_").replace("-", "_"), path)
+        name.replace(".", "_").replace("-", "_"), path)
     mod = importlib.util.module_from_spec(spec)
     sys.modules[spec.name] = mod
     spec.loader.exec_module(mod)
@@ -70,6 +73,10 @@ class Spec:
 
     def metric_module(self, name: str):
         return load_module(self.bench_dir / "metrics" / f"{name}.py")
+
+    def family_module(self):
+        return load_module(self.bench_dir / "families"
+                           / f"{self.conf['family']}.py")
 
     def reference_module(self):
         return load_module(self.bench_dir / "reference" / f"{self.conf['family']}.py")
@@ -122,26 +129,6 @@ def enable_compile_cache() -> None:
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
 
 
-def program_config(conf: dict):
-    """The program's `ModelConfig` for a configuration file."""
-    from repro.models import ModelConfig
-    act = {"silu": "silu", "gelu_pytorch_tanh": "gelu"}[conf["hidden_act"]]
-    cfg = ModelConfig(
-        name=conf["name"], family="dense",
-        n_layers=conf["num_hidden_layers"], d_model=conf["hidden_size"],
-        n_heads=conf["num_attention_heads"],
-        n_kv_heads=conf["num_key_value_heads"], head_dim=conf["head_dim"],
-        d_ff=conf["intermediate_size"], vocab_size=conf["vocab_size"],
-        rope_theta=float(conf["rope_theta"]),
-        sliding_window=int(conf["sliding_window"] or 0),
-        attn_bias=bool(conf["attention_bias"]), mlp_act=act,
-        gated_mlp=conf["mlp"] == "gated",
-        norm_eps=conf.get("rms_norm_eps", conf.get("norm_epsilon")),
-        tie_embeddings=bool(conf["tie_word_embeddings"]),
-        dtype=conf["torch_dtype"], **conf["program"])
-    return cfg
-
-
 @dataclasses.dataclass
 class Run:
     """What one run recorded; the metric readers take it. Times are
@@ -163,6 +150,7 @@ class Run:
     spans: list | None = None          # engine spans (trace runs)
     span_offset: float = 0.0           # perf_counter of the tracer's origin
     reduced: Any = None                # xplane.Reduced (trace runs)
+    trace_events: Any = None           # xplane.Events (trace runs)
     peaks: Any = None
     memory_peak_bytes: int = 0
     drain_end: float = 0.0
@@ -186,6 +174,12 @@ class Run:
             if s >= self.window0 and e <= self.window1:
                 out.append((s, e, ev.attrs))
         return out
+
+    def spans_in_trace(self, kind: str) -> list:
+        """`spans_in_window` of those that end inside the part of the
+        window that the device trace holds."""
+        end = self.window0 + self.reduced.traced_s
+        return [sp for sp in self.spans_in_window(kind) if sp[1] <= end]
 
     def counts(self):
         return self.spec.counts_module()
@@ -230,15 +224,17 @@ def run_cell(spec: Spec, seed: int, seconds: float, trace: bool, *,
 
     sys.path.insert(0, str(spec.bench_dir.parent))
     weights_mod = load_module(spec.bench_dir / "weights.py")
+    family = spec.family_module()
     conf, mix = spec.conf, spec.mix
     serving = conf["serving"]
     traffic = spec.traffic_module()
-    cfg = program_config(conf)
+    cfg = family.program_config(conf)
     parts = {}
 
     t = time.perf_counter()
-    weights = weights_mod.make_weights(conf, cfg.padded_vocab, seed)
-    params = weights_mod.to_program_tree(weights, conf, param_shape_structs(cfg))
+    weights = weights_mod.make_weights(
+        family.leaf_table(conf, cfg.padded_vocab), conf["torch_dtype"], seed)
+    params = family.to_program_tree(weights, conf, param_shape_structs(cfg))
     parts["init_s"] = time.perf_counter() - t
     log(f"init: {sum(a.size for a in jax.tree.leaves(weights)) / 1e9:.3f}B "
         f"parameters made on the device in {parts['init_s']:.2f} s")
@@ -382,12 +378,24 @@ def run_cell(spec: Spec, seed: int, seconds: float, trace: bool, *,
 
     if trace:
         xplane = load_module(spec.bench_dir / "xplane.py")
-        run.reduced = xplane.reduce_trace(xplane.find_xplane(str(profile_dir)))
+        t = time.perf_counter()
+        run.trace_events = xplane.read_events(
+            xplane.find_xplane(str(profile_dir)))
+        run.reduced = r = xplane.reduce_trace(run.trace_events)
+        log(f"trace: the device plane holds {r.traced_s!r} s of the "
+            f"{r.window_s!r} s window ({'cut' if r.span.cut else 'whole'}"
+            f"), {r.span.decode_runs} decode runs; "
+            f"{r.span.unreadable() or 'runs and syncs paired'}; read in "
+            f"{time.perf_counter() - t:.1f} s")
         run.peaks = peaks or load_module(
             spec.bench_dir / "peaks.py").peaks_for(d.device_kind)
-        device["busy_s"] = run.reduced.busy_s
-        device["window_s"] = run.reduced.window_s
+        # both over the part of the window that the trace holds, so that
+        # their ratio is the idle share there; `traced_s` names that part
+        device["busy_s"] = r.busy_s
+        device["window_s"] = r.traced_s
+        device["traced_s"] = r.traced_s
         metrics = read_metrics(spec.per_layer, run)
+        run.trace_events = None
     else:
         metrics = read_metrics(spec.end_to_end, run)
     result["metrics"] = metrics

@@ -40,31 +40,50 @@ def decode_step_ms(run) -> float | None:
     return statistics.median(e - s for s, e, _ in spans) * 1e3
 
 
-def idle_share(run) -> float | None:
+def device_trace(run, name: str):
+    """The run's reduced trace (`xplane.Reduced`) where a device-trace
+    metric may be read from it; None, with the reason on standard error,
+    where the run has no trace, the device plane holds less than
+    `xplane.MIN_TRACED_S` of the window, or its decode runs and syncs do
+    not pair."""
     r = run.reduced
-    if r is None or r.window_s <= 0:
+    if r is None:
         return None
-    return (1.0 - r.busy_s / r.window_s) * 100.0
+    why = r.span.unreadable()
+    if why:
+        print(f"metric {name}: not read from the trace: {why}",
+              file=sys.stderr)
+        return None
+    return r
+
+
+def idle_share(run, name: str) -> float | None:
+    """Share of the traced part of the window with no operation on the
+    device."""
+    r = device_trace(run, name)
+    if r is None:
+        return None
+    return (1.0 - r.busy_s / r.traced_s) * 100.0
 
 
 def decode_steps(run) -> list[tuple[float, float]]:
-    """(FLOPs, bytes) of each decode step in the window. A step's span
-    records each live slot's position after it, so the token it
-    processed sat one position earlier."""
+    """(FLOPs, bytes) of each decode step in the traced part of the
+    window. A step's span records each live slot's position after it, so
+    the token it processed sat one position earlier."""
     counts, conf = run.counts(), run.spec.conf
     return [counts.decode_step(conf, [p - 1 for p in attrs["positions"]])
-            for _, _, attrs in run.spans_in_window("decode_step")]
+            for _, _, attrs in run.spans_in_trace("decode_step")]
 
 
 def prefill_flops(run) -> tuple[float, int]:
     counts = run.counts()
-    spans = run.spans_in_window("prefill_step")
+    spans = run.spans_in_trace("prefill_step")
     return (sum(counts.prefill(run.spec.conf, a["prompt_len"])[0]
                 for _, _, a in spans), len(spans))
 
 
 def program_seconds(run, marker: str) -> float:
     """Device seconds of every run of the programs whose name holds
-    `marker`, inside the traced window."""
+    `marker`, inside the traced part of the window."""
     r = run.reduced
     return sum(s for name, s in r.program_s.items() if marker in name)

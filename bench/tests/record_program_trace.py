@@ -175,7 +175,7 @@ def main(out: str) -> None:
             evs = list(line.events)
             print("   line", line.name, len(evs),
                   sorted({e.name for e in evs})[:8])
-    pi = program_idle(str(dst))
+    pi = program_idle(xplane.read_events(str(dst)))
     print(json.dumps({"bytes": dst.stat().st_size,
                       "tokens": [g.out_tokens for g in reqs],
                       "program_idle": pi and vars(pi)}, indent=1))

@@ -63,7 +63,7 @@ def main(out: str) -> None:
             evs = list(line.events)
             print("   line", line.name, len(evs),
                   sorted({e.name for e in evs})[:8])
-    r = xplane.reduce_trace(path)
+    r = xplane.reduce_trace(xplane.read_events(path))
     print(json.dumps({"window_s": r.window_s, "busy_s": r.busy_s,
                       "program_s": r.program_s,
                       "program_runs": r.program_runs,

@@ -12,16 +12,19 @@ import pytest
 
 from conftest import DATA
 
-from bench import harness, weights as weights_mod
+from bench import weights as weights_mod
+from bench.families import dense as dense_family
 from bench.reference import dense
 
 
 def _engine(conf, seed):
     from repro.models.transformer import param_shape_structs
     from repro.serve import ServeEngine
-    cfg = harness.program_config(conf)
-    w = weights_mod.make_weights(conf, cfg.padded_vocab, seed)
-    params = weights_mod.to_program_tree(w, conf, param_shape_structs(cfg))
+    cfg = dense_family.program_config(conf)
+    w = weights_mod.make_weights(
+        dense_family.leaf_table(conf, cfg.padded_vocab), conf["torch_dtype"],
+        seed)
+    params = dense_family.to_program_tree(w, conf, param_shape_structs(cfg))
     eng = ServeEngine(cfg, params, batch_slots=conf["serving"]["slots"],
                       max_len=conf["serving"]["max_len"], engine="jit")
     return cfg, w, eng
@@ -64,7 +67,8 @@ def test_reference_matches_served_logits(name):
 
 def test_float8_control_departs_from_reference():
     conf = json.loads((DATA / "granite-tiny.json").read_text())
-    w = weights_mod.make_weights(conf, 512, 7)
+    w = weights_mod.make_weights(dense_family.leaf_table(conf, 512),
+                                 conf["torch_dtype"], 7)
     toks = np.arange(30, dtype=np.int32)
     ref = dense.logits(w, conf, toks, range(30), bucket=32)
     ctl = dense.logits(w, conf, toks, range(30), control=True, bucket=32)

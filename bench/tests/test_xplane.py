@@ -18,7 +18,7 @@ TRACE = str(DATA / "small.xplane.pb")
 
 @pytest.fixture(scope="module")
 def reduced():
-    return xplane.reduce_trace(TRACE)
+    return xplane.reduce_trace(xplane.read_events(TRACE))
 
 
 def _raw(line_name):
@@ -51,10 +51,15 @@ def test_busy_time_is_the_union_of_op_intervals(reduced):
             grid[int((s - w0) // 10):int((e - w0) // 10)] = True
     assert reduced.n_devices == 1
     assert reduced.window_s == pytest.approx((w1 - w0) * 1e-9)
+    # no host sync ends after the plane's last event: read whole
+    assert not reduced.span.cut
+    assert reduced.traced_s == reduced.window_s
     assert reduced.busy_s == pytest.approx(grid.sum() * 1e-8, rel=0.02)
     assert 0 < reduced.busy_s < reduced.window_s
     idle = sum(s for _, s in reduced.idle_by_label)
     assert idle == pytest.approx(reduced.window_s - reduced.busy_s, rel=1e-6)
+    # and too short a window for the metrics to be read from it
+    assert "under 15.0 s" in reduced.span.unreadable()
 
 
 def test_program_time_and_runs(reduced):
